@@ -19,6 +19,7 @@ from benchmarks.common import cached
 _CHILD = r"""
 import os, sys, json, time
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%d"
+os.environ["JAX_PLATFORMS"] = "cpu"  # forced host devices exist only on the CPU
 sys.path.insert(0, "src")
 import numpy as np, jax, jax.numpy as jnp
 from repro.core import MSLRUConfig, init_table

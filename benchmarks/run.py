@@ -5,6 +5,11 @@
 Results are cached under results/bench/ so re-runs are instant; --force
 recomputes.  Output: human-readable report + ``name,us_per_call,derived``
 CSV lines at the end.
+
+``fig14`` and ``sharded`` are CPU count runs: each starts a child process
+with ``JAX_PLATFORMS=cpu`` and forced host devices, which exist only on the
+CPU.  On a TPU host the parent process keeps the chip and the children
+never ask for it.
 """
 
 from __future__ import annotations

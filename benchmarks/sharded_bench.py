@@ -93,6 +93,7 @@ BENCH_JSON = Path(__file__).resolve().parent.parent / "BENCH_sharded.json"
 _CHILD = r"""
 import os, sys, json
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=%(ndev)d"
+os.environ["JAX_PLATFORMS"] = "cpu"  # forced host devices exist only on the CPU
 sys.path.insert(0, "src")
 import numpy as np
 from repro.core import MSLRUConfig

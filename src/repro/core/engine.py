@@ -255,7 +255,9 @@ from repro.core.multistep import (  # noqa: F401  (OP_* re-exported)
     row_access,
     row_apply,
     row_lookup,
+    set_columns,
     set_index_for,
+    set_rows,
 )
 
 __all__ = [
@@ -300,15 +302,19 @@ def make_sequential_engine(cfg: MSLRUConfig, with_ops: bool = False):
     """
     a, c = cfg.assoc, cfg.planes
 
-    def one(table, qkey, qval, op, live, cost):
+    def one(view, qkey, qval, op, live, cost):
+        # the scan carries the (C*A, S) column view (see set_columns), so
+        # each step reads and writes one set column in the table's layout
         sid = set_index_for(cfg, qkey[None])[0]
-        rows = jax.lax.dynamic_slice(table, (sid, 0, 0), (1, a, c))
+        col = jax.lax.dynamic_slice(view, (0, sid), (c * a, 1))
+        rows = set_rows(col, a, c)
         # row_apply is the single op-dispatch used by every engine, so the
         # sequential oracle and the batched paths cannot drift per-op.
         new_rows, res = row_apply(cfg, rows, qkey[None], qval[None], op[None],
                                   chain_live=live[None], costs=cost[None])
-        table = jax.lax.dynamic_update_slice(table, new_rows, (sid, 0, 0))
-        return table, (res.hit[0], res.pos[0], res.value[0],
+        view = jax.lax.dynamic_update_slice(view, set_columns(new_rows),
+                                            (0, sid))
+        return view, (res.hit[0], res.pos[0], res.value[0],
                        res.evicted_key[0], res.evicted_val[0],
                        res.evicted_valid[0])
 
@@ -319,9 +325,9 @@ def make_sequential_engine(cfg: MSLRUConfig, with_ops: bool = False):
         def step(tbl, xs):
             k, v, op, lv, cc = xs
             return one(tbl, k, v, op, lv, cc)
-        table, outs = jax.lax.scan(
-            step, table, (qkeys, qvals, opcodes, live, costs))
-        return table, SeqOutputs(*outs)
+        view, outs = jax.lax.scan(
+            step, set_columns(table), (qkeys, qvals, opcodes, live, costs))
+        return set_rows(view, a, c), SeqOutputs(*outs)
 
     if with_ops:
         @jax.jit

@@ -131,6 +131,24 @@ def init_table(cfg: MSLRUConfig) -> jnp.ndarray:
     return t.at[:, :, 0].set(EMPTY_KEY)
 
 
+def set_columns(table: jnp.ndarray) -> jnp.ndarray:
+    """(S, A, C) table -> its (C*A, S) plane view, one column per set.
+
+    On TPU the table's HBM layout keeps the set axis minor (the compact
+    layout of a shape whose trailing axes are small), so this view costs
+    nothing, and gathering or updating set *columns* of it leaves that
+    layout alone; a row gather on the (S, A, C) shape instead re-lays the
+    whole table out with its trailing axis padded to 128 lanes (21x at
+    A=8, C=3)."""
+    s, a, c = table.shape
+    return jnp.transpose(table, (2, 1, 0)).reshape(c * a, s)
+
+
+def set_rows(view: jnp.ndarray, a: int, c: int) -> jnp.ndarray:
+    """Inverse of ``set_columns``."""
+    return jnp.transpose(view.reshape(c, a, -1), (2, 1, 0))
+
+
 def set_index_for(cfg: MSLRUConfig, qkeys: jnp.ndarray) -> jnp.ndarray:
     """Set assignment by MurmurHash3 finalizer over key plane(s). qkeys: (B, KP)."""
     if cfg.key_planes == 1:

@@ -34,15 +34,19 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.core.multistep import AccessResult, MSLRUConfig, set_index_for
+from repro.core.multistep import (AccessResult, MSLRUConfig, set_columns,
+                                  set_index_for, set_rows)
 from repro.core.engine import (batched_rounds_update, make_batched_engine,
                                sorted_group_ranks)
 from repro.core.invector import EMPTY_KEY
 from repro.kernels.msl_cache import (
     _chain_body,
     _chain_state0,
+    _kernel_block,
+    cols_to_planes,
     msl_access_kernel_call,
     msl_onepass_kernel_call,
+    split_planes,
 )
 from repro.kernels.ref import msl_access_ref
 
@@ -75,22 +79,32 @@ def msl_access(rows, qkeys, qvals, *, cfg: MSLRUConfig, ops=None,
 # One-pass conflict-aware update
 # ---------------------------------------------------------------------------
 
-def _chain_resolve_xla(cfg: MSLRUConfig, rows, qk, qv, ops, lrank, served,
+def _chain_resolve_xla(cfg: MSLRUConfig, planes, qk, qv, ops, lrank, served,
                        n_rounds, chain_live=None, costs=None):
-    """jnp mirror of the one-pass kernel: the same ``_chain_body`` loop, run
-    in XLA over the whole sorted batch (no blocks, so no carry needed).
+    """jnp mirror of the one-pass kernel: the same ``_chain_body`` loop, in
+    the kernel's plane layout, run in XLA over the whole sorted batch (no
+    blocks, so no carry needed).
 
-    rows (B, A, C) sorted-by-set gathered rows; ops (B,) sorted opcodes;
+    planes (C, A, B) sorted-by-set gathered rows; ops (B,) sorted opcodes;
     lrank (B,) chain rank; served (B,) bool; n_rounds: dynamic trip count
     (max chain length); chain_live (B,) optional sorted execute mask for
     the CHAIN_GET/CHAIN_PUT rows; costs (B,) optional sorted insert costs.
-    Returns (rows_after, hit_i32, pos, value, ev) like the kernel.
+    Returns (planes_after, hit_i32, pos, value, ev) like the kernel.
     """
+    v = cfg.value_planes
+
+    def row(x):
+        return None if x is None else x[None, :]
+
     _, after, h, po, va, ev = jax.lax.fori_loop(
         0, n_rounds,
-        _chain_body(cfg, qk, qv, ops, lrank, served, chain_live, costs),
-        _chain_state0(cfg, rows))
-    return after, h, po, va[:, : cfg.value_planes], ev
+        _chain_body(cfg, split_planes(cols_to_planes(qk)),
+                    split_planes(cols_to_planes(qv)), row(ops), row(lrank),
+                    row(served), row(chain_live), row(costs)),
+        _chain_state0(cfg, split_planes(planes)))
+    return (jnp.stack(after), h[0], po[0],
+            jnp.concatenate(va, axis=0).T[:, :v],
+            jnp.concatenate(ev, axis=0).T)
 
 
 def onepass_update(cfg: MSLRUConfig, table, gsid, valid, qkeys, qvals,
@@ -123,7 +137,7 @@ def onepass_update(cfg: MSLRUConfig, table, gsid, valid, qkeys, qvals,
         costs = jnp.asarray(costs, jnp.int32)
 
     # --- prologue: pad, sort by set id, derive duplicate-chain metadata ---
-    bb = min(block_b, b) if use_kernel else b
+    bb = _kernel_block(b, block_b) if use_kernel else b
     pad = (-b) % bb
     bp = b + pad
     if pad:
@@ -159,30 +173,40 @@ def onepass_update(cfg: MSLRUConfig, table, gsid, valid, qkeys, qvals,
     # rank 0 there and is re-seeded from the kernel's cross-block carry
     lrank = jnp.where(svalid, jnp.minimum(offset, i % bb), 0)
 
-    # --- one gather: a live row per *distinct* set (chain heads); everyone
-    # else reads the dummy row and is resolved on-chip -----------------
-    padded = jnp.concatenate([table, jnp.zeros((1,) + table.shape[1:], table.dtype)])
-    rows_in = jnp.take(padded, jnp.where(firsts, ssid, s), axis=0)
+    # --- one gather: a live row per *distinct* set (chain heads), as
+    # columns of the table's plane view (see set_columns) straight into the
+    # kernel layout; everyone else reads index S (zeros) and is resolved
+    # on-chip --------------------------------------------------------------
+    a, c = cfg.assoc, cfg.planes
+    view = set_columns(table)
+    planes_in = jnp.take(view, jnp.where(firsts, ssid, s), axis=1,
+                         mode="fill", fill_value=0).reshape(c, a, bp)
 
     # --- resolve chains on-chip -------------------------------------------
     if use_kernel:
         if interpret is None:
             interpret = _on_cpu()
         nrounds_blocks = lrank.reshape(bp // bb, bb).max(axis=1).astype(jnp.int32) + 1
-        rows_after, hit, pos, val, ev = msl_onepass_kernel_call(
-            rows_in, sqk, sqv, sops, ssid, lrank.astype(jnp.int32),
-            served_s.astype(jnp.int32), nrounds_blocks, slive, sqc,
+        # does each block's first query continue the previous block's chain?
+        heads = ssid[::bb]
+        cont = jnp.concatenate([jnp.zeros((1,), bool),
+                                heads[1:] == ssid[bb - 1:-1:bb]])
+        planes_after, hit, pos, val, ev = msl_onepass_kernel_call(
+            planes_in, sqk, sqv, sops, lrank.astype(jnp.int32),
+            served_s.astype(jnp.int32), nrounds_blocks,
+            cont.astype(jnp.int32), slive, sqc,
             cfg=cfg, block_b=bb, interpret=interpret)
     else:
-        rows_after, hit, pos, val, ev = _chain_resolve_xla(
-            cfg, rows_in, sqk, sqv, sops, lrank, served_s, n_valid_rounds,
+        planes_after, hit, pos, val, ev = _chain_resolve_xla(
+            cfg, planes_in, sqk, sqv, sops, lrank, served_s, n_valid_rounds,
             slive, sqc)
 
     # --- one scatter: each chain's tail commits its set's final row -------
     lasts = jnp.concatenate([ssid[:-1] != ssid[1:], jnp.ones((1,), bool)])
-    scatter_sid = jnp.where(lasts, ssid, s)     # non-tails pile on the dummy
-    padded = padded.at[scatter_sid].set(rows_after)
-    table = padded[:-1]
+    scatter_sid = jnp.where(lasts, ssid, s)     # non-tails: index S, dropped
+    view = view.at[:, scatter_sid].set(planes_after.reshape(c * a, bp),
+                                        mode="drop")
+    table = set_rows(view, a, c)
 
     # --- unsort outputs; unserved queries report like the rounds engine ---
     inv = jnp.zeros((bp,), jnp.int32).at[order].set(i)

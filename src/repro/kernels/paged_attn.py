@@ -15,11 +15,15 @@ contiguous copy of its sequence:
 
 The kernel carries the flash-attention ``(m, l, acc)`` running triple in
 f32 VMEM scratch across the sequential inner grid dimension and writes the
-normalized context at the final step.  Numerics: identical score math to
-``models.attention.paged_attn_decode`` (scale in q dtype, f32 scores,
-optional tanh softcap, NEG_INF masking) but flash-accumulation ordering
-instead of a full-lane softmax, so outputs agree to ~1e-5 (tests gate
-argmax equality + allclose against the jnp mirror, which in turn is
+normalized context at the final step.  GQA without reshapes across the
+tiled axes: the wrapper regroups q to ``(B, rep, KVH, Dh)``, and for each
+of the ``rep`` query heads per KV group the scores are a lane reduction of
+``q * k`` over Dh on a ``(page_tokens, KVH, Dh)`` block.  Numerics: the
+score math of ``models.attention.paged_attn_decode`` (scale in q dtype,
+optional tanh softcap, NEG_INF masking), but scores, probabilities and the
+context stay f32 where the mirror rounds them to bf16, and accumulation is
+flash-ordered, so outputs agree at bf16 resolution (tests gate argmax
+equality + allclose against the jnp mirror, which in turn is
 bit-identical to the contiguous oracle).
 
 Masked lanes use a *finite* NEG_INF (-1e30), so a block with no valid lane
@@ -54,9 +58,7 @@ def _paged_attn_kernel(n_prefix_blocks, n_tail_blocks, page_tokens, softcap,
     b = pl.program_id(0)
     j = pl.program_id(1)
     pt = page_tokens
-    h, dh = q_ref.shape[1], q_ref.shape[2]
-    kvh = pk_ref.shape[2]
-    rep = h // kvh
+    rep, kvh = q_ref.shape[1], q_ref.shape[2]
 
     @pl.when(j == 0)
     def _init():
@@ -70,42 +72,41 @@ def _paged_attn_kernel(n_prefix_blocks, n_tail_blocks, page_tokens, softcap,
     wnd = wnd_ref[0]
 
     # both candidate blocks are in VMEM (the pipeline fetched them); pick one
-    k_blk = jnp.where(is_tail, tk_ref[0], pk_ref[0])      # (pt, KVH, Dh)
-    v_blk = jnp.where(is_tail, tv_ref[0], pv_ref[0])
+    k_blk = jnp.where(is_tail, tk_ref[0], pk_ref[0]).astype(jnp.float32)
+    v_blk = jnp.where(is_tail, tv_ref[0], pv_ref[0]).astype(jnp.float32)
 
-    lane = jax.lax.broadcasted_iota(jnp.int32, (1, pt), 1)[0]
+    # token t of the block on the leading axis: (pt, KVH, 1)
+    t = jax.lax.broadcasted_iota(jnp.int32, (pt, kvh, 1), 0)
     base = jnp.where(is_tail, plen + (j - n_prefix_blocks) * pt, j * pt)
-    pos = base + lane                                      # absolute positions
-    valid = jnp.where(is_tail, pos <= cur, pos < plen)
-    valid &= jnp.where(wnd > 0, cur - pos < wnd, True)
+    pos = base + t                                          # absolute positions
+    valid = pos < jnp.where(is_tail, cur + 1, plen)        # no i1 selects
+    valid &= (cur - pos < wnd) | (wnd <= 0)
 
-    q = q_ref[0]                                           # (H, Dh), pre-scaled
-    qg = q.reshape(kvh, rep, dh)
-    s = jnp.einsum("grd,tgd->grt", qg.astype(jnp.float32),
-                   k_blk.astype(jnp.float32)).reshape(h, pt)
-    if softcap > 0.0:
-        s = jnp.tanh(s / softcap) * softcap
-    s = jnp.where(valid[None, :], s, NEG_INF)
+    # One pass per query head of each KV group; q_ref[0, r] holds head
+    # g * rep + r for every group g, so scores are a lane reduction of
+    # q * k over Dh and never regroup heads across sublanes.
+    for r in range(rep):
+        q = q_ref[0, r].astype(jnp.float32)                 # (KVH, Dh), pre-scaled
+        s = jnp.sum(q[None] * k_blk, axis=-1, keepdims=True)  # (pt, KVH, 1)
+        if softcap > 0.0:
+            s = jnp.tanh(s / softcap) * softcap
+        s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_ref[:, 0]
-    l_prev = l_ref[:, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1))
-    # NEG_INF is finite: zero masked lanes explicitly (see module docstring)
-    p = jnp.where(valid[None, :], jnp.exp(s - m_new[:, None]), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_new = alpha * l_prev + jnp.sum(p, axis=1)
-    pg = p.reshape(kvh, rep, pt)
-    delta = jnp.einsum("grt,tgd->grd", pg,
-                       v_blk.astype(jnp.float32)).reshape(h, dh)
-    acc_ref[...] = alpha[:, None] * acc_ref[...] + delta
-    m_ref[...] = jnp.broadcast_to(m_new[:, None], m_ref.shape)
-    l_ref[...] = jnp.broadcast_to(l_new[:, None], l_ref.shape)
+        m_prev = m_ref[r]                                   # (KVH, 1)
+        l_prev = l_ref[r]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=0))
+        # NEG_INF is finite: zero masked lanes explicitly (see module docstring)
+        p = jnp.where(valid, jnp.exp(s - m_new[None]), 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[r] = alpha * l_prev + jnp.sum(p, axis=0)
+        acc_ref[r] = alpha * acc_ref[r] + jnp.sum(p * v_blk, axis=0)
+        m_ref[r] = m_new
 
     @pl.when(j == n_prefix_blocks + n_tail_blocks - 1)
     def _finish():
-        l = l_ref[:, 0]
-        l = jnp.where(l == 0.0, 1.0, l)                    # dead rows -> 0 out
-        out_ref[...] = (acc_ref[...] / l[:, None]).astype(out_ref.dtype)[None]
+        l = l_ref[...]
+        l = jnp.where(l == 0.0, 1.0, l)                     # dead rows -> 0 out
+        out_ref[0] = (acc_ref[...] / l).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit,
@@ -133,7 +134,9 @@ def paged_attn_decode_call(q, pool_k, pool_v, block_table, tail_k, tail_v,
         padw = ((0, 0), (0, ntb * pt - tmax), (0, 0), (0, 0))
         tail_k, tail_v = jnp.pad(tail_k, padw), jnp.pad(tail_v, padw)
     scale = jnp.asarray(dh ** -0.5, q.dtype)
-    qs = q * scale
+    rep = h // kvh
+    # (B, H, Dh) -> (B, rep, KVH, Dh): head g * rep + r at [r, g]
+    qs = (q * scale).reshape(b, kvh, rep, dh).transpose(0, 2, 1, 3)
 
     bt = jnp.asarray(block_table, jnp.int32)
     plen = jnp.broadcast_to(jnp.asarray(prefix_len, jnp.int32), (b,))
@@ -142,7 +145,7 @@ def paged_attn_decode_call(q, pool_k, pool_v, block_table, tail_k, tail_v,
            else jnp.asarray(window, jnp.int32).reshape(1))
 
     def q_map(i, j, bt_s, pl_s, cu_s, wd_s):
-        return (i, 0, 0)
+        return (i, 0, 0, 0)
 
     def pool_map(i, j, bt_s, pl_s, cu_s, wd_s):
         # prefix steps walk the block table; tail steps park on an
@@ -157,22 +160,23 @@ def paged_attn_decode_call(q, pool_k, pool_v, block_table, tail_k, tail_v,
         num_scalar_prefetch=4,
         grid=(b, npb + ntb),
         in_specs=[
-            pl.BlockSpec((1, h, dh), q_map),
+            pl.BlockSpec((1, rep, kvh, dh), q_map),
             pl.BlockSpec((1, pt, kvh, dh), pool_map),
             pl.BlockSpec((1, pt, kvh, dh), pool_map),
             pl.BlockSpec((1, pt, kvh, dh), tail_map),
             pl.BlockSpec((1, pt, kvh, dh), tail_map),
         ],
-        out_specs=pl.BlockSpec((1, h, dh), q_map),
+        out_specs=pl.BlockSpec((1, rep, kvh, dh), q_map),
         scratch_shapes=[
-            pltpu.VMEM((h, 128), jnp.float32),   # running max m
-            pltpu.VMEM((h, 128), jnp.float32),   # running denom l
-            pltpu.VMEM((h, dh), jnp.float32),    # unnormalized context
+            pltpu.VMEM((rep, kvh, 1), jnp.float32),    # running max m
+            pltpu.VMEM((rep, kvh, 1), jnp.float32),    # running denom l
+            pltpu.VMEM((rep, kvh, dh), jnp.float32),   # unnormalized context
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_paged_attn_kernel, npb, ntb, pt, softcap),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, rep, kvh, dh), q.dtype),
         interpret=interpret,
     )(bt, plen, cur, wnd, qs, pool_k, pool_v, tail_k, tail_v)
+    return out.transpose(0, 2, 1, 3).reshape(b, h, dh)

@@ -27,30 +27,15 @@ AXIS_MODEL = "model"
 
 
 def make_mesh_compat(shape, axes):
-    """jax.make_mesh with Auto axis types where the jax version has them."""
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    """``jax.make_mesh`` with every axis of type Auto."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def shard_map_compat(f, mesh, in_specs, out_specs):
-    """shard_map across jax versions (>=0.6 top-level vs experimental),
-    always with the replication check disabled (check_vma / check_rep,
-    whichever this version spells it)."""
-    if hasattr(jax, "shard_map"):
-        for kw in ({"check_vma": False}, {"check_rep": False}):
-            try:
-                return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                     out_specs=out_specs, **kw)
-            except TypeError:
-                continue
-        # last resort: no disable kwarg recognized; let real errors propagate
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     check_rep=False)
+    """``jax.shard_map`` with the replication check off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -62,7 +47,8 @@ def make_production_mesh(*, multi_pod: bool = False):
 def make_cache_mesh(n_devices: int | None = None):
     """1-D mesh over all (or n) devices for the sharded key-value cache.
 
-    For CPU-only multi-device runs (the sharded tests / benches), set
+    On a TPU host these are the real chips.  For CPU-only multi-device runs
+    (the sharded tests / benches), set
     ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` BEFORE the first
     jax import — the fake-device count is locked at backend init, which is
     why those runs live in subprocesses (see tests/test_sharded_engine.py

@@ -1,14 +1,16 @@
-"""Serving driver: ``python -m repro.launch.serve --arch <id> --smoke``.
+"""Serving entry point: ``python -m repro.launch.serve --arch <id> [--no-smoke]``.
 
 Brings up the continuous-batching engine with the multi-step-LRU prefix
 cache and runs a synthetic request workload (shared-prefix templates with
 zipfian popularity — the cache's favourable regime, and exactly the shape
-of production prompt traffic).
+of production prompt traffic).  ``serve(args)`` is the same run in-process
+(``chip_smoke.py`` drives it that way).
 """
 
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import numpy as np
@@ -20,12 +22,23 @@ from repro.serving.engine import Request, ServeEngine
 from repro.serving.kv_cache import PagedKVPool
 from repro.serving.prefix_cache import PrefixCache
 from repro.data.ycsb import zipfian
+from repro.launch.compile_cache import use_compile_cache
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="phi3-mini-3.8b")
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="the arch's toy SMOKE config (default); "
+                         "--no-smoke runs its published widths")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut the depth to this many layers (0 = the "
+                         "config's own depth)")
+    ap.add_argument("--slots", type=int, default=4)
+    ap.add_argument("--max-len", type=int, default=256)
+    ap.add_argument("--pool-pages", type=int, default=256,
+                    help="pages of --chunk-tokens tokens in the KV pool")
     ap.add_argument("--requests", type=int, default=24)
     ap.add_argument("--templates", type=int, default=8)
     ap.add_argument("--prefix-tokens", type=int, default=64)
@@ -54,10 +67,14 @@ def main():
                          "pool — zero gather copies, one resident copy of "
                          "a hot prefix however many slots borrow it "
                          "(requires the prefix cache)")
+    ap.add_argument("--paged-kernel", action="store_true",
+                    help="paged decode attention through the Pallas kernel "
+                         "instead of its jnp mirror (needs --kv-mode paged)")
     ap.add_argument("--sharded", type=int, default=0, metavar="D",
-                    help="back the prefix cache with a D-device "
-                         "ShardedCacheClient (needs XLA_FLAGS="
-                         "--xla_force_host_platform_device_count=D on CPU)")
+                    help="back the prefix cache with a ShardedCacheClient "
+                         "over D devices: on a TPU host, D real chips; on "
+                         "the CPU, forced host devices (XLA_FLAGS="
+                         "--xla_force_host_platform_device_count=D)")
     ap.add_argument("--cap", type=float, default=0.0,
                     help="per-peer cap multiplier for --sharded "
                          "(0 = 'full', no shedding)")
@@ -76,15 +93,49 @@ def main():
                          "faults apply at tick boundaries")
     ap.add_argument("--chaos-events", type=int, default=3,
                     help="events in the seeded FaultPlan")
-    args = ap.parse_args()
+    return ap
 
-    cfg = get_config(args.arch, smoke=True)
+
+def check_args(ap: argparse.ArgumentParser, args) -> None:
+    if args.kv_mode == "paged" and args.no_prefix_cache:
+        ap.error("--kv-mode paged requires the prefix cache (the pool is "
+                 "the resident prefix store)")
+    if args.paged_kernel and args.kv_mode != "paged":
+        ap.error("--paged-kernel needs --kv-mode paged")
+    if args.throttle_threshold > 0 and not args.sharded:
+        ap.error("--throttle-threshold needs --sharded (pressure comes "
+                 "from the sharded backend's load mirror)")
+    if args.chaos_seed >= 0 and not args.sharded:
+        ap.error("--chaos-seed needs --sharded (fault targets)")
+
+
+def build_model(args):
+    """(model, params) for ``args``: the arch config (SMOKE or published
+    widths, depth cut by ``--layers``) with random weights from seed 0,
+    drawn inside one jitted init so the f32 draws never sit on the device
+    all at once."""
+    cfg = get_config(args.arch, smoke=args.smoke)
+    if args.layers:
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
     model = make_model(cfg)
-    params = model.init(jax.random.PRNGKey(0))
+    params = jax.jit(model.init)(jax.random.PRNGKey(0))
+    return model, params
+
+
+def serve(args, model=None, params=None) -> ServeEngine:
+    """Serve the synthetic workload of ``args`` to completion; returns the
+    drained engine (``engine.finished`` holds the requests)."""
+    if model is None:
+        model, params = build_model(args)
+    cfg = model.cfg
+    # the cache kernel runs where it compiles; on the CPU its jnp mirror
+    # (bit-identical) stands in for the Pallas interpreter
+    cache_kernel = jax.default_backend() == "tpu"
 
     pool = pc = None
     if not args.no_prefix_cache:
-        pool = PagedKVPool(cfg, n_pages=256, page_tokens=args.chunk_tokens)
+        pool = PagedKVPool(cfg, n_pages=args.pool_pages,
+                           page_tokens=args.chunk_tokens)
         backend = None
         if args.sharded:
             from repro.core.multistep import MSLRUConfig
@@ -92,20 +143,16 @@ def main():
             from repro.launch.mesh import make_cache_mesh
             backend = ShardedCacheClient(
                 MSLRUConfig(num_sets=256, m=2, p=4, value_planes=1),
-                make_cache_mesh(args.sharded),
+                make_cache_mesh(args.sharded), use_kernel=cache_kernel,
                 cap=(args.cap if args.cap > 0 else "full"),
                 placement=args.placement)
         pc = PrefixCache(num_sets=256, m=2, p=4,
-                         chunk_tokens=args.chunk_tokens, backend=backend)
-    if args.kv_mode == "paged" and args.no_prefix_cache:
-        ap.error("--kv-mode paged requires the prefix cache (the pool is "
-                 "the resident prefix store)")
-    if args.throttle_threshold > 0 and not args.sharded:
-        ap.error("--throttle-threshold needs --sharded (pressure comes "
-                 "from the sharded backend's load mirror)")
-    eng = ServeEngine(model, params, slots=4, max_len=256,
+                         chunk_tokens=args.chunk_tokens, backend=backend,
+                         use_kernel=cache_kernel)
+    eng = ServeEngine(model, params, slots=args.slots, max_len=args.max_len,
                       prefix_cache=pc, pool=pool,
                       decode_mode=args.decode_mode, kv_mode=args.kv_mode,
+                      paged_kernel=args.paged_kernel,
                       max_window=args.max_window,
                       throttle_threshold=(args.throttle_threshold
                                           if args.throttle_threshold > 0
@@ -113,7 +160,6 @@ def main():
 
     plan = None
     if args.chaos_seed >= 0:
-        assert args.sharded, "--chaos-seed needs --sharded (fault targets)"
         from repro.launch.elastic import FaultPlan
         plan = FaultPlan.seeded(args.chaos_seed, ticks=args.requests,
                                 ndev=args.sharded,
@@ -168,6 +214,15 @@ def main():
               f"fallback_rate={st['fallback_rate']:.3f}")
     if pc:
         print(f"[serve] prefix cache: {pc.stats()}")
+    return eng
+
+
+def main(argv=None):
+    ap = build_parser()
+    args = ap.parse_args(argv)
+    check_args(ap, args)
+    use_compile_cache()
+    serve(args)
 
 
 if __name__ == "__main__":

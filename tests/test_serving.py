@@ -449,3 +449,29 @@ def test_prefix_reuse_equals_vanilla_decode():
     eng2.run_until_done()
     reused = [x for x in eng.finished if x.rid == 2][0]
     assert reused.out_tokens == r.out_tokens
+
+
+@pytest.mark.parametrize("mode", [
+    ["--kv-mode", "contiguous", "--decode-mode", "inflight"],
+    ["--kv-mode", "paged", "--decode-mode", "megastep"],
+])
+def test_serve_entry_point(mode):
+    """``launch.serve.serve`` — the path ``chip_smoke.py`` drives in-process
+    — serves every request, with prefill skipped by the prefix cache."""
+    from repro.launch.serve import build_parser, serve
+    args = build_parser().parse_args(
+        ["--requests", "4", "--templates", "1", "--max-new", "3",
+         "--layers", "1", "--slots", "2", "--max-len", "128",
+         "--pool-pages", "32"] + mode)
+    eng = serve(args)
+    assert eng.model.cfg.n_layers == 1
+    assert sorted(r.rid for r in eng.finished) == [0, 1, 2, 3]
+    assert all(len(r.out_tokens) == 3 for r in eng.finished)
+    assert sum(r.prefill_skipped for r in eng.finished) > 0
+
+
+def test_serve_smoke_flag():
+    from repro.launch.serve import build_parser
+    ap = build_parser()
+    assert ap.parse_args([]).smoke
+    assert not ap.parse_args(["--no-smoke"]).smoke
