@@ -10,6 +10,7 @@ Public API:
 from __future__ import annotations
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 
 from repro.core.multistep import (  # noqa: F401
@@ -80,18 +81,21 @@ class MultiStepLRUCache:
         chain_ids (B,) segment ids for CHAIN_GET/CHAIN_PUT rows (the fused
         serving tick — see the chain contract in engine.py); costs (B,)
         per-query insert costs (needs ``cfg.cost_planes`` — see the cost
-        plane contract in engine.py)."""
-        keys = self._canon_keys(keys)
-        if vals is None:
-            vals = np.zeros((keys.shape[0], self.cfg.value_planes), np.int32)
-        if ops is not None:
-            ops = jnp.asarray(ops, jnp.int32)
-        if costs is not None:
-            costs = jnp.asarray(costs, jnp.int32)
-        self.table, res = self._batched(self.table, keys,
-                                        jnp.asarray(vals, jnp.int32), ops,
-                                        chain_ids, costs)
-        return res
+        plane contract in engine.py).  The call is the host span
+        ``msl.access`` in a profiler trace."""
+        with jax.profiler.TraceAnnotation("msl.access"):
+            keys = self._canon_keys(keys)
+            if vals is None:
+                vals = np.zeros((keys.shape[0], self.cfg.value_planes),
+                                np.int32)
+            if ops is not None:
+                ops = jnp.asarray(ops, jnp.int32)
+            if costs is not None:
+                costs = jnp.asarray(costs, jnp.int32)
+            self.table, res = self._batched(self.table, keys,
+                                            jnp.asarray(vals, jnp.int32), ops,
+                                            chain_ids, costs)
+            return res
 
     # -- exact sequential path -------------------------------------------------
     def access_seq(self, keys: np.ndarray, vals: np.ndarray | None = None,

@@ -575,17 +575,19 @@ def make_batched_engine(cfg: MSLRUConfig, max_rounds: int | None = None,
         # ops=None is a distinct (static) pytree structure: the ACCESS-only
         # specialization compiles with no opcode operand at all (likewise
         # costs=None compiles no cost operand).
-        sids = set_index_for(cfg, qkeys)
-        valid = jnp.ones(sids.shape, bool)
+        with jax.named_scope("msl.order"):
+            sids = set_index_for(cfg, qkeys)
+            valid = jnp.ones(sids.shape, bool)
         table, res, _served = update(table, sids, valid, qkeys, qvals, ops,
                                      costs=costs)
         return table, res
 
     @jax.jit
     def run_chain(table, qkeys, qvals, ops, chain_ids, costs):
-        sids = set_index_for(cfg, qkeys)
-        valid = jnp.ones(sids.shape, bool)
-        live = chain_live_mask(cfg, table, qkeys, ops, chain_ids)
+        with jax.named_scope("msl.order"):
+            sids = set_index_for(cfg, qkeys)
+            valid = jnp.ones(sids.shape, bool)
+            live = chain_live_mask(cfg, table, qkeys, ops, chain_ids)
         table, res, _served = update(table, sids, valid, qkeys, qvals, ops,
                                      chain_live=live, costs=costs)
         return table, res

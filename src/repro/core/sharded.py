@@ -199,36 +199,40 @@ def make_sharded_engine(cfg: MSLRUConfig, mesh, axis: str = "cache", cap: int | 
         Returns (routed rows (ndev*k, pc), didx, sidx, served) — didx/sidx
         address the slot each local query landed in, for the result unpack.
         """
-        sid = set_index_for(cfg, qkeys)                     # (q,) global set id
-        owner = sid // s_local                              # destination shard
-        # slot within the per-destination send buffer = rank among same-owner
-        onehot = (owner[:, None] == jnp.arange(ndev)[None, :])
-        rank = jnp.cumsum(onehot, axis=0)                   # 1-based rank
-        slot = jnp.sum(jnp.where(onehot, rank - 1, 0), axis=1)
-        served = slot < k                                   # overflow -> shed
+        with jax.named_scope("msl.route"):
+            sid = set_index_for(cfg, qkeys)                 # (q,) set id
+            owner = sid // s_local                          # destination shard
+            # slot within the per-destination send buffer = rank among
+            # same-owner queries
+            onehot = (owner[:, None] == jnp.arange(ndev)[None, :])
+            rank = jnp.cumsum(onehot, axis=0)               # 1-based rank
+            slot = jnp.sum(jnp.where(onehot, rank - 1, 0), axis=1)
+            served = slot < k                               # overflow -> shed
 
-        payload = jnp.concatenate([qkeys] + extra_planes, axis=-1)
-        pc = payload.shape[-1]
-        # one SACRIFICIAL column (k) catches every overflow row's scatter:
-        # clamping overflow to a real slot would clobber the admitted row
-        # that legitimately occupies it (silently dropping its op while it
-        # reports served=True) — the dump column is sliced off before the
-        # all_to_all, so duplicate overflow scatters there are harmless
-        send = jnp.full((ndev, k + 1, pc), EMPTY_KEY, jnp.int32)
-        send = send.at[owner, jnp.where(served, slot, k)].set(payload)
-        didx = owner
-        sidx = jnp.where(served, slot, k - 1)               # clamp: unpack read
-        recv = jax.lax.all_to_all(send[:, :k], axis, split_axis=0,
-                                  concat_axis=0, tiled=True)
-        return recv.reshape(ndev * k, pc), didx, sidx, served
+            payload = jnp.concatenate([qkeys] + extra_planes, axis=-1)
+            pc = payload.shape[-1]
+            # one SACRIFICIAL column (k) catches every overflow row's
+            # scatter: clamping overflow to a real slot would clobber the
+            # admitted row that legitimately occupies it (silently dropping
+            # its op while it reports served=True) — the dump column is
+            # sliced off before the all_to_all, so duplicate overflow
+            # scatters there are harmless
+            send = jnp.full((ndev, k + 1, pc), EMPTY_KEY, jnp.int32)
+            send = send.at[owner, jnp.where(served, slot, k)].set(payload)
+            didx = owner
+            sidx = jnp.where(served, slot, k - 1)           # clamp: unpack read
+            recv = jax.lax.all_to_all(send[:, :k], axis, split_axis=0,
+                                      concat_axis=0, tiled=True)
+            return recv.reshape(ndev * k, pc), didx, sidx, served
 
     def _route_back(planes, didx, sidx, k):
         """all_to_all per-routed-row result planes back to the sources."""
-        back = jax.lax.all_to_all(
-            jnp.concatenate(planes, axis=-1).reshape(ndev, k, -1),
-            axis, split_axis=0, concat_axis=0, tiled=True)
-        # back[d, j] = result of the query I sent to shard d in slot j
-        return back[didx, sidx]
+        with jax.named_scope("msl.route"):
+            back = jax.lax.all_to_all(
+                jnp.concatenate(planes, axis=-1).reshape(ndev, k, -1),
+                axis, split_axis=0, concat_axis=0, tiled=True)
+            # back[d, j] = result of the query I sent to shard d in slot j
+            return back[didx, sidx]
 
     def local_fn(table, qkeys, qvals, ops=None, chain_ids=None, order=None,
                  costs=None):
@@ -246,52 +250,58 @@ def make_sharded_engine(cfg: MSLRUConfig, mesh, axis: str = "cache", cap: int | 
             # contract requires, globally.  (Read-only, so the canonical
             # ``order`` sort is not needed here.)
             rq, didx, sidx, served = _route(qkeys, [], k)
-            p_keys = rq[:, :kp]
-            p_valid = p_keys[:, 0] != EMPTY_KEY
-            p_rows = jnp.take(table, set_index_for(cfg, p_keys) % s_local,
-                              axis=0)
-            p_hit, _, _ = row_lookup(cfg, p_rows, p_keys)
-            hit_home = _route_back(
-                [(p_hit & p_valid).astype(jnp.int32)[:, None]],
-                didx, sidx, k)
-            raw_hit = (hit_home[:, 0] != 0) & served
-            live = chain_exec_from_hits(ops, chain_ids, raw_hit,
-                                        valid=served)
-            live_planes = [live.astype(jnp.int32)[:, None]]
+            with jax.named_scope("msl.order"):
+                p_keys = rq[:, :kp]
+                p_valid = p_keys[:, 0] != EMPTY_KEY
+                p_rows = jnp.take(table,
+                                  set_index_for(cfg, p_keys) % s_local,
+                                  axis=0)
+                p_hit, _, _ = row_lookup(cfg, p_rows, p_keys)
+                p_planes = [(p_hit & p_valid).astype(jnp.int32)[:, None]]
+            hit_home = _route_back(p_planes, didx, sidx, k)
+            with jax.named_scope("msl.order"):
+                raw_hit = (hit_home[:, 0] != 0) & served
+                live = chain_exec_from_hits(ops, chain_ids, raw_hit,
+                                            valid=served)
+                live_planes = [live.astype(jnp.int32)[:, None]]
 
-        planes = ([qvals] + ([] if ops is None else [ops[:, None]])
-                  + live_planes
-                  + ([] if costs is None else [costs[:, None]])
-                  + ([] if order is None else [order[:, None]]))
+        with jax.named_scope("msl.route"):
+            planes = ([qvals] + ([] if ops is None else [ops[:, None]])
+                      + live_planes
+                      + ([] if costs is None else [costs[:, None]])
+                      + ([] if order is None else [order[:, None]]))
         rq, didx, sidx, served = _route(qkeys, planes, k)
-        r_keys, r_vals = rq[:, :kp], rq[:, kp: kp + v]
-        valid = r_keys[:, 0] != EMPTY_KEY
-        r_ops = (None if ops is None
-                 else jnp.where(valid, rq[:, kp + v], OP_ACCESS))
-        r_live = (jnp.where(valid, rq[:, kp + v + 1], 0)
-                  if chain_mode else None)
-        cost_col = (kp + v + (0 if ops is None else 1)
-                    + (1 if chain_mode else 0))
-        r_cost = (None if costs is None
-                  else jnp.where(valid, rq[:, cost_col], 0))
+        with jax.named_scope("msl.route"):
+            r_keys, r_vals = rq[:, :kp], rq[:, kp: kp + v]
+            valid = r_keys[:, 0] != EMPTY_KEY
+            r_ops = (None if ops is None
+                     else jnp.where(valid, rq[:, kp + v], OP_ACCESS))
+            r_live = (jnp.where(valid, rq[:, kp + v + 1], 0)
+                      if chain_mode else None)
+            cost_col = (kp + v + (0 if ops is None else 1)
+                        + (1 if chain_mode else 0))
+            r_cost = (None if costs is None
+                      else jnp.where(valid, rq[:, cost_col], 0))
 
-        lsid = set_index_for(cfg, r_keys) % s_local
+        with jax.named_scope("msl.order"):
+            lsid = set_index_for(cfg, r_keys) % s_local
         if order is not None:
             # canonical arrival order: stably sort the routed rows by their
             # caller-order rank before the update, so same-set duplicate
             # chains resolve exactly as the sequential engine would no
             # matter which source device each row came from; unsort the
             # results so the route-back addressing stays (didx, sidx).
-            ord_col = cost_col + (0 if costs is None else 1)
-            r_ord = jnp.where(valid, rq[:, ord_col], _INT32_MAX)
-            perm = jnp.argsort(r_ord, stable=True)
-            inv = jnp.argsort(perm)
-            table, res, _served = update(
-                table, lsid[perm], valid[perm], r_keys[perm], r_vals[perm],
-                None if r_ops is None else r_ops[perm],
-                chain_live=None if r_live is None else r_live[perm],
-                costs=None if r_cost is None else r_cost[perm])
-            res = jax.tree.map(lambda a: a[inv], res)
+            with jax.named_scope("msl.order"):
+                ord_col = cost_col + (0 if costs is None else 1)
+                r_ord = jnp.where(valid, rq[:, ord_col], _INT32_MAX)
+                perm = jnp.argsort(r_ord, stable=True)
+                inv = jnp.argsort(perm)
+                rows = [None if x is None else x[perm] for x in
+                        (lsid, valid, r_keys, r_vals, r_ops, r_live, r_cost)]
+            table, res, _served = update(table, *rows[:5],
+                                         chain_live=rows[5], costs=rows[6])
+            with jax.named_scope("msl.order"):
+                res = jax.tree.map(lambda a: a[inv], res)
         else:
             # exact local update (same conflict schemes as the batched
             # engine); arrival order (source-device, slot) is already the
@@ -300,26 +310,27 @@ def make_sharded_engine(cfg: MSLRUConfig, mesh, axis: str = "cache", cap: int | 
                                          r_ops, chain_live=r_live,
                                          costs=r_cost)
 
-        hit_back = (res.hit & valid).astype(jnp.int32)[:, None]
-        val_back = (res.value if v else
-                    jnp.zeros((res.value.shape[0], 1), jnp.int32))
-        # shed rows' unpack reads a clamped slot (another row's result):
-        # zero every plane for them — the contract is a plain miss with
-        # all-zero fields when served is False
-        zero = served[:, None]
-        if chain_mode:
-            evv_back = (res.evicted_val if v else
-                        jnp.zeros((res.value.shape[0], 1), jnp.int32))
-            evok_back = (res.evicted_valid & valid).astype(jnp.int32)[:, None]
-            home = _route_back([hit_back, val_back, evv_back, evok_back],
-                               didx, sidx, k)
+        with jax.named_scope("msl.route"):
+            back = [(res.hit & valid).astype(jnp.int32)[:, None],
+                    (res.value if v else
+                     jnp.zeros((res.value.shape[0], 1), jnp.int32))]
+            if chain_mode:
+                back += [(res.evicted_val if v else
+                          jnp.zeros((res.value.shape[0], 1), jnp.int32)),
+                         (res.evicted_valid & valid).astype(jnp.int32)[:, None]]
+        home = _route_back(back, didx, sidx, k)
+        with jax.named_scope("msl.route"):
+            # shed rows' unpack reads a clamped slot (another row's result):
+            # zero every plane for them — the contract is a plain miss with
+            # all-zero fields when served is False
+            zero = served[:, None]
             my_hit = home[:, 0].astype(bool) & served
-            return (table, my_hit, jnp.where(zero, home[:, 1: 1 + ve], 0),
-                    served, jnp.where(zero, home[:, 1 + ve: 1 + 2 * ve], 0),
-                    (home[:, 1 + 2 * ve] != 0) & served)
-        home = _route_back([hit_back, val_back], didx, sidx, k)
-        my_hit = home[:, 0].astype(bool) & served
-        return table, my_hit, jnp.where(zero, home[:, 1:], 0), served
+            if chain_mode:
+                return (table, my_hit,
+                        jnp.where(zero, home[:, 1: 1 + ve], 0), served,
+                        jnp.where(zero, home[:, 1 + ve: 1 + 2 * ve], 0),
+                        (home[:, 1 + 2 * ve] != 0) & served)
+            return table, my_hit, jnp.where(zero, home[:, 1:], 0), served
 
     out_specs = (P(axis, None, None), P(axis), P(axis, None), P(axis))
     out_specs_chain = out_specs + (P(axis, None), P(axis))
@@ -354,11 +365,12 @@ def make_sharded_engine(cfg: MSLRUConfig, mesh, axis: str = "cache", cap: int | 
             assert ops is not None, "order requires an ops vector"
         if chain_ids is not None:
             assert ops is not None, "chain_ids requires an ops vector"
-        fn = _variant(ops is not None, chain_ids is not None,
-                      order is not None, costs is not None)
-        extra = [jnp.asarray(x, jnp.int32)
-                 for x in (ops, chain_ids, costs, order) if x is not None]
-        return fn(table, qkeys, qvals, *extra)
+        with jax.profiler.TraceAnnotation("msl.access"):
+            fn = _variant(ops is not None, chain_ids is not None,
+                          order is not None, costs is not None)
+            extra = [jnp.asarray(x, jnp.int32)
+                     for x in (ops, chain_ids, costs, order) if x is not None]
+            return fn(table, qkeys, qvals, *extra)
 
     return run
 

@@ -426,6 +426,7 @@ def msl_access_kernel_call(rows, qkeys, qvals, ops=None, chain_live=None,
         out_specs=out_specs,
         out_shape=out_shapes,
         interpret=interpret,
+        name="msl_access_kernel_call",
     )(rows_to_planes(rows), cols_to_planes(qkeys), cols_to_planes(qvals_e),
       *extra)
     planes_o, *rest = _unpack_outputs(out, b, v)
@@ -528,6 +529,7 @@ def msl_onepass_kernel_call(planes, qkeys, qvals, ops, lrank, served, nrounds,
         grid_spec=grid_spec,
         out_shape=out_shapes,
         interpret=interpret,
+        name="msl_onepass_kernel_call",
     )(nrounds, cont, planes, cols_to_planes(qkeys),
       cols_to_planes(qvals_e), *extra, lrank[None, :], served[None, :])
     return _unpack_outputs(out, b, v)

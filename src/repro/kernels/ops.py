@@ -27,6 +27,14 @@ rounds engine (they previously diverged on capped/padded streams).
 The gather/scatter around the kernels stays in XLA, which is the intended
 TPU decomposition (dynamic row indexing is an XLA strength; the dense lane
 arithmetic is the kernel's job).
+
+Every op of ``onepass_update`` runs under exactly one of five named scopes,
+which the compiled program keeps in each op's name path (a profiler trace
+shows them as e.g. ``jit(run_ops)/msl.scatter/scatter``): ``msl.order``
+(sort, chain metadata, unsort), ``msl.table`` (the set-column view and its
+inverse, which is where an undonated call copies the table), ``msl.gather``,
+``msl.chain`` (the kernel or its jnp mirror) and ``msl.scatter``.  A fusion
+takes the name of its root op.
 """
 
 from __future__ import annotations
@@ -129,101 +137,115 @@ def onepass_update(cfg: MSLRUConfig, table, gsid, valid, qkeys, qvals,
     s = table.shape[0]
     b = gsid.shape[0]
     kp, v = cfg.key_planes, cfg.value_planes
-    if ops is not None:  # None stays None: ACCESS-only specialization
-        ops = jnp.asarray(ops, jnp.int32)
-    if chain_live is not None:
-        chain_live = jnp.asarray(chain_live, jnp.int32)
-    if costs is not None:
-        costs = jnp.asarray(costs, jnp.int32)
-
-    # --- prologue: pad, sort by set id, derive duplicate-chain metadata ---
+    a, c = cfg.assoc, cfg.planes
     bb = _kernel_block(b, block_b) if use_kernel else b
     pad = (-b) % bb
     bp = b + pad
-    if pad:
-        gsid = jnp.concatenate([gsid, jnp.zeros((pad,), gsid.dtype)])
-        valid = jnp.concatenate([valid, jnp.zeros((pad,), bool)])
-        qkeys = jnp.concatenate([qkeys, jnp.zeros((pad, kp), jnp.int32)])
-        qvals = jnp.concatenate([qvals, jnp.zeros((pad, v), jnp.int32)])
-        if ops is not None:
-            ops = jnp.concatenate([ops, jnp.zeros((pad,), jnp.int32)])
+
+    # --- prologue: pad, sort by set id, derive duplicate-chain metadata ---
+    with jax.named_scope("msl.order"):
+        if ops is not None:  # None stays None: ACCESS-only specialization
+            ops = jnp.asarray(ops, jnp.int32)
         if chain_live is not None:
-            chain_live = jnp.concatenate(
-                [chain_live, jnp.zeros((pad,), jnp.int32)])
+            chain_live = jnp.asarray(chain_live, jnp.int32)
         if costs is not None:
-            costs = jnp.concatenate([costs, jnp.zeros((pad,), jnp.int32)])
+            costs = jnp.asarray(costs, jnp.int32)
+        if pad:
+            gsid = jnp.concatenate([gsid, jnp.zeros((pad,), gsid.dtype)])
+            valid = jnp.concatenate([valid, jnp.zeros((pad,), bool)])
+            qkeys = jnp.concatenate([qkeys, jnp.zeros((pad, kp), jnp.int32)])
+            qvals = jnp.concatenate([qvals, jnp.zeros((pad, v), jnp.int32)])
+            if ops is not None:
+                ops = jnp.concatenate([ops, jnp.zeros((pad,), jnp.int32)])
+            if chain_live is not None:
+                chain_live = jnp.concatenate(
+                    [chain_live, jnp.zeros((pad,), jnp.int32)])
+            if costs is not None:
+                costs = jnp.concatenate(
+                    [costs, jnp.zeros((pad,), jnp.int32)])
 
-    i = jnp.arange(bp, dtype=jnp.int32)
-    sid_key = jnp.where(valid, gsid, s).astype(jnp.int32)  # invalid -> dummy
-    order = jnp.argsort(sid_key, stable=True)
-    ssid = sid_key[order]
-    svalid = valid[order]
-    sqk = qkeys[order]
-    sqv = qvals[order]
-    sops = None if ops is None else ops[order]
-    slive = None if chain_live is None else chain_live[order]
-    sqc = None if costs is None else costs[order]
+        i = jnp.arange(bp, dtype=jnp.int32)
+        # invalid -> dummy
+        sid_key = jnp.where(valid, gsid, s).astype(jnp.int32)
+        order = jnp.argsort(sid_key, stable=True)
+        ssid = sid_key[order]
+        svalid = valid[order]
+        sqk = qkeys[order]
+        sqv = qvals[order]
+        sops = None if ops is None else ops[order]
+        slive = None if chain_live is None else chain_live[order]
+        sqc = None if costs is None else costs[order]
 
-    firsts, offset = sorted_group_ranks(ssid)   # chain heads + chain ranks
-    n_valid_rounds = jnp.max(jnp.where(svalid, offset, -1)) + 1
-    n_rounds = (jnp.minimum(n_valid_rounds, max_rounds)
-                if max_rounds is not None else n_valid_rounds)
-    served_s = svalid & (offset < n_rounds)
-    # block-local chain rank: a chain crossing a block boundary restarts at
-    # rank 0 there and is re-seeded from the kernel's cross-block carry
-    lrank = jnp.where(svalid, jnp.minimum(offset, i % bb), 0)
+        firsts, offset = sorted_group_ranks(ssid)   # chain heads + chain ranks
+        n_valid_rounds = jnp.max(jnp.where(svalid, offset, -1)) + 1
+        n_rounds = (jnp.minimum(n_valid_rounds, max_rounds)
+                    if max_rounds is not None else n_valid_rounds)
+        served_s = svalid & (offset < n_rounds)
+        # block-local chain rank: a chain crossing a block boundary restarts
+        # at rank 0 there and is re-seeded from the kernel's cross-block carry
+        lrank = jnp.where(svalid, jnp.minimum(offset, i % bb), 0)
 
     # --- one gather: a live row per *distinct* set (chain heads), as
     # columns of the table's plane view (see set_columns) straight into the
     # kernel layout; everyone else reads index S (zeros) and is resolved
     # on-chip --------------------------------------------------------------
-    a, c = cfg.assoc, cfg.planes
-    view = set_columns(table)
-    planes_in = jnp.take(view, jnp.where(firsts, ssid, s), axis=1,
-                         mode="fill", fill_value=0).reshape(c, a, bp)
+    with jax.named_scope("msl.table"):
+        view = set_columns(table)
+    with jax.named_scope("msl.gather"):
+        planes_in = jnp.take(view, jnp.where(firsts, ssid, s), axis=1,
+                             mode="fill", fill_value=0).reshape(c, a, bp)
 
     # --- resolve chains on-chip -------------------------------------------
     if use_kernel:
         if interpret is None:
             interpret = _on_cpu()
-        nrounds_blocks = lrank.reshape(bp // bb, bb).max(axis=1).astype(jnp.int32) + 1
-        # does each block's first query continue the previous block's chain?
-        heads = ssid[::bb]
-        cont = jnp.concatenate([jnp.zeros((1,), bool),
-                                heads[1:] == ssid[bb - 1:-1:bb]])
-        planes_after, hit, pos, val, ev = msl_onepass_kernel_call(
-            planes_in, sqk, sqv, sops, lrank.astype(jnp.int32),
-            served_s.astype(jnp.int32), nrounds_blocks,
-            cont.astype(jnp.int32), slive, sqc,
-            cfg=cfg, block_b=bb, interpret=interpret)
+        with jax.named_scope("msl.order"):
+            nrounds_blocks = (lrank.reshape(bp // bb, bb).max(axis=1)
+                              .astype(jnp.int32) + 1)
+            # does each block's first query continue the previous block's
+            # chain?
+            heads = ssid[::bb]
+            cont = jnp.concatenate([jnp.zeros((1,), bool),
+                                    heads[1:] == ssid[bb - 1:-1:bb]])
+        with jax.named_scope("msl.chain"):
+            planes_after, hit, pos, val, ev = msl_onepass_kernel_call(
+                planes_in, sqk, sqv, sops, lrank.astype(jnp.int32),
+                served_s.astype(jnp.int32), nrounds_blocks,
+                cont.astype(jnp.int32), slive, sqc,
+                cfg=cfg, block_b=bb, interpret=interpret)
     else:
-        planes_after, hit, pos, val, ev = _chain_resolve_xla(
-            cfg, planes_in, sqk, sqv, sops, lrank, served_s, n_valid_rounds,
-            slive, sqc)
+        with jax.named_scope("msl.chain"):
+            planes_after, hit, pos, val, ev = _chain_resolve_xla(
+                cfg, planes_in, sqk, sqv, sops, lrank, served_s,
+                n_valid_rounds, slive, sqc)
 
     # --- one scatter: each chain's tail commits its set's final row -------
-    lasts = jnp.concatenate([ssid[:-1] != ssid[1:], jnp.ones((1,), bool)])
-    scatter_sid = jnp.where(lasts, ssid, s)     # non-tails: index S, dropped
-    view = view.at[:, scatter_sid].set(planes_after.reshape(c * a, bp),
-                                        mode="drop")
-    table = set_rows(view, a, c)
+    with jax.named_scope("msl.scatter"):
+        lasts = jnp.concatenate([ssid[:-1] != ssid[1:], jnp.ones((1,), bool)])
+        scatter_sid = jnp.where(lasts, ssid, s)  # non-tails: index S, dropped
+        view = view.at[:, scatter_sid].set(planes_after.reshape(c * a, bp),
+                                            mode="drop")
+    with jax.named_scope("msl.table"):
+        table = set_rows(view, a, c)
 
     # --- unsort outputs; unserved queries report like the rounds engine ---
-    inv = jnp.zeros((bp,), jnp.int32).at[order].set(i)
+    with jax.named_scope("msl.order"):
+        inv = jnp.zeros((bp,), jnp.int32).at[order].set(i)
 
-    def unsort(x):
-        return x[inv][:b]
+        def unsort(x):
+            return x[inv][:b]
 
-    served = unsort(served_s)
-    hit_u, pos_u, val_u, ev_u = unsort(hit), unsort(pos), unsort(val), unsort(ev)
-    res = AccessResult(
-        hit=(hit_u != 0) & served,
-        value=jnp.where(served[:, None], val_u, 0) if v else val_u,
-        pos=jnp.where(served, pos_u, -1),
-        evicted_key=jnp.where(served[:, None], ev_u[:, :kp], 0),
-        evicted_val=jnp.where(served[:, None], ev_u[:, kp:kp + v], 0),
-        evicted_valid=served & (ev_u[:, 0] != EMPTY_KEY),
-    )
+        served = unsort(served_s)
+        hit_u, pos_u = unsort(hit), unsort(pos)
+        val_u, ev_u = unsort(val), unsort(ev)
+        res = AccessResult(
+            hit=(hit_u != 0) & served,
+            value=jnp.where(served[:, None], val_u, 0) if v else val_u,
+            pos=jnp.where(served, pos_u, -1),
+            evicted_key=jnp.where(served[:, None], ev_u[:, :kp], 0),
+            evicted_val=jnp.where(served[:, None], ev_u[:, kp:kp + v], 0),
+            evicted_valid=served & (ev_u[:, 0] != EMPTY_KEY),
+        )
     return table, res, served
 
 

@@ -178,5 +178,6 @@ def paged_attn_decode_call(q, pool_k, pool_v, block_table, tail_k, tail_v,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, rep, kvh, dh), q.dtype),
         interpret=interpret,
+        name="paged_attn_decode_call",
     )(bt, plen, cur, wnd, qs, pool_k, pool_v, tail_k, tail_v)
     return out.transpose(0, 2, 1, 3).reshape(b, h, dh)

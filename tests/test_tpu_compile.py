@@ -170,22 +170,68 @@ def _phi3(n_layers):
                                n_layers=n_layers)
 
 
-def test_paged_attn_kernel_compiles_phi3(one_chip):
-    from repro.kernels.paged_attn import paged_attn_decode_call
+def _paged_args(sharding):
     cfg = _phi3(1)
     h, kvh, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     bf16 = jnp.bfloat16
-    args = (_spec(one_chip, (SLOTS, h, dh), bf16),
-            _spec(one_chip, (PAGES, PAGE_TOKENS, kvh, dh), bf16),
-            _spec(one_chip, (PAGES, PAGE_TOKENS, kvh, dh), bf16),
-            _spec(one_chip, (SLOTS, MAX_LEN // PAGE_TOKENS)),
-            _spec(one_chip, (SLOTS, MAX_LEN, kvh, dh), bf16),
-            _spec(one_chip, (SLOTS, MAX_LEN, kvh, dh), bf16),
-            _spec(one_chip, (SLOTS,)), _spec(one_chip, (SLOTS,)))
+    return (_spec(sharding, (SLOTS, h, dh), bf16),
+            _spec(sharding, (PAGES, PAGE_TOKENS, kvh, dh), bf16),
+            _spec(sharding, (PAGES, PAGE_TOKENS, kvh, dh), bf16),
+            _spec(sharding, (SLOTS, MAX_LEN // PAGE_TOKENS)),
+            _spec(sharding, (SLOTS, MAX_LEN, kvh, dh), bf16),
+            _spec(sharding, (SLOTS, MAX_LEN, kvh, dh), bf16),
+            _spec(sharding, (SLOTS,)), _spec(sharding, (SLOTS,)))
+
+
+def test_paged_attn_kernel_compiles_phi3(one_chip):
+    from repro.kernels.paged_attn import paged_attn_decode_call
     compiled = jax.jit(
         lambda *xs: paged_attn_decode_call(*xs, interpret=False)
-    ).lower(*args).compile()
+    ).lower(*_paged_args(one_chip)).compile()
     _assert_kernel(compiled)
+
+
+def _kernel_names(compiled) -> list:
+    """The compiled kernels' op names as a device trace shows them, without
+    the ``.N`` that XLA appends."""
+    return re.findall(r'^\s*(?:ROOT )?%([A-Za-z_]\w*?)(?:\.\d+)? = .*'
+                      r'custom_call_target="tpu_custom_call"',
+                      compiled.as_text(), re.M)
+
+
+@pytest.mark.parametrize("kernel", ["msl_access_kernel_call",
+                                    "msl_onepass_kernel_call",
+                                    "paged_attn_decode_call"])
+def test_kernel_op_name_is_the_kernels_own(one_chip, kernel):
+    """The trace readers find a kernel by its op name.  The name is the
+    ``pallas_call``'s own, so it holds whatever Python function calls the
+    kernel: here the undecorated function, inside a jit of another name."""
+    from repro.kernels import msl_cache, paged_attn
+    cfg = CACHE_CFG
+    if kernel == "paged_attn_decode_call":
+        raw = paged_attn.paged_attn_decode_call.__wrapped__
+        args = _paged_args(one_chip)
+
+        def call(*xs):
+            return raw(*xs, interpret=False)
+    elif kernel == "msl_access_kernel_call":
+        raw = msl_cache.msl_access_kernel_call.__wrapped__
+        args = (_spec(one_chip, (BATCH, cfg.assoc, cfg.planes)),
+                _spec(one_chip, (BATCH, cfg.key_planes)),
+                _spec(one_chip, (BATCH, cfg.value_planes)))
+
+        def call(*xs):
+            return raw(*xs, cfg=cfg, interpret=False)
+    else:
+        raw = msl_cache.msl_onepass_kernel_call.__wrapped__
+        args = [x for x in _onepass_args(one_chip, cfg, 2048, False, False,
+                                         False) if x is not None]
+
+        def call(planes, qk, qv, lrank, served, nrounds, cont):
+            return raw(planes, qk, qv, None, lrank, served, nrounds, cont,
+                       cfg=cfg, block_b=2048, interpret=False)
+    compiled = jax.jit(call).lower(*args).compile()
+    assert _kernel_names(compiled) == [kernel]
 
 
 def test_phi3_decode_step_compiles(one_chip):
