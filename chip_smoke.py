@@ -141,7 +141,9 @@ def phase_cache(num_sets=NUM_SETS, batch=BATCH, n_batches=N_BATCHES,
         sl = slice(i * batch, (i + 1) * batch)
         t = time.perf_counter()
         res = cache.access(keys[sl], vals[sl])
-        jax.block_until_ready((cache.table, res))
+        # the call's table is ready with res; reading cache.table here
+        # would lend it out, and the next call would copy it
+        jax.block_until_ready(res)
         t_batches.append(time.perf_counter() - t)
         hits.append(np.asarray(res.hit))
         values.append(np.asarray(res.value))

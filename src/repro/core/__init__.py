@@ -58,18 +58,36 @@ class MultiStepLRUCache:
 
     >>> cache = MultiStepLRUCache(MSLRUConfig(num_sets=1024, m=2, p=4))
     >>> res = cache.access(np.array([42]))
+
+    The wrapper owns its table and donates it to each batched call, so the
+    call writes the new table in place instead of copying the old one.
+    ``cache.table`` is a value: reading or assigning it lends that array
+    out, and the next call then donates a copy of it, never the array
+    itself.
     """
 
     def __init__(self, cfg: MSLRUConfig, engine: str = "onepass",
                  use_kernel: bool = False):
         self.cfg = cfg
-        self.table = init_table(cfg)
+        self._table = init_table(cfg)
+        self._lent = False
         self._seq = make_sequential_engine(cfg, with_ops=True)
         # one-pass conflict resolution (bit-exact with the rounds engine,
         # one HBM gather/scatter per batch); the jnp chain is the default —
         # ``use_kernel=True`` routes it through the Pallas kernel
         self._batched = make_batched_engine(cfg, engine=engine,
-                                            use_kernel=use_kernel)
+                                            use_kernel=use_kernel).donating
+
+    @property
+    def table(self):
+        """The (S, A, C) table; it stays valid after later calls."""
+        self._lent = True
+        return self._table
+
+    @table.setter
+    def table(self, table):
+        self._table = table
+        self._lent = True
 
     # -- batched high-throughput path ----------------------------------------
     def access(self, keys: np.ndarray, vals: np.ndarray | None = None,
@@ -92,9 +110,12 @@ class MultiStepLRUCache:
                 ops = jnp.asarray(ops, jnp.int32)
             if costs is not None:
                 costs = jnp.asarray(costs, jnp.int32)
-            self.table, res = self._batched(self.table, keys,
-                                            jnp.asarray(vals, jnp.int32), ops,
-                                            chain_ids, costs)
+            # a lent table is never donated: the call takes a copy of it
+            table = jnp.copy(self._table) if self._lent else self._table
+            self._table, res = self._batched(table, keys,
+                                             jnp.asarray(vals, jnp.int32),
+                                             ops, chain_ids, costs)
+            self._lent = False
             return res
 
     # -- exact sequential path -------------------------------------------------
@@ -108,8 +129,8 @@ class MultiStepLRUCache:
             ops = np.full((n,), OP_ACCESS, np.int32)
         if costs is not None:
             costs = jnp.asarray(costs, jnp.int32)
-        self.table, out = self._seq(
-            self.table, keys, jnp.asarray(vals, jnp.int32),
+        self._table, out = self._seq(
+            self._table, keys, jnp.asarray(vals, jnp.int32),
             jnp.asarray(ops, jnp.int32), chain_ids, costs)
         return out
 
@@ -122,5 +143,5 @@ class MultiStepLRUCache:
 
     @property
     def occupancy(self) -> float:
-        valid = np.asarray(self.table[:, :, 0] != EMPTY_KEY)
+        valid = np.asarray(self._table[:, :, 0] != EMPTY_KEY)
         return float(valid.mean())

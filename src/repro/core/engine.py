@@ -566,11 +566,16 @@ def make_batched_engine(cfg: MSLRUConfig, max_rounds: int | None = None,
     omitted means all OP_ACCESS.  ``chain_ids`` (B,) enables the fused
     chain ops (CHAIN_GET/CHAIN_PUT): the longest-prefix scan runs on device
     inside the same jit'd call — one engine invocation per serving tick.
+
+    ``run`` leaves the caller's table valid, so XLA copies it whole inside
+    every call.  ``run.donating`` is the same programs (same names) with
+    ``table`` donated: the new table is written into the old one's buffer,
+    which is deleted.  It is for the table's owner (``MultiStepLRUCache``).
+    Each keeps its two jitted programs as ``.run_ops`` and ``.run_chain``.
     """
     update = make_conflict_update(cfg, engine, max_rounds, use_kernel,
                                   block_b, interpret)
 
-    @jax.jit
     def run_ops(table, qkeys, qvals, ops, costs):
         # ops=None is a distinct (static) pytree structure: the ACCESS-only
         # specialization compiles with no opcode operand at all (likewise
@@ -582,7 +587,6 @@ def make_batched_engine(cfg: MSLRUConfig, max_rounds: int | None = None,
                                      costs=costs)
         return table, res
 
-    @jax.jit
     def run_chain(table, qkeys, qvals, ops, chain_ids, costs):
         with jax.named_scope("msl.order"):
             sids = set_index_for(cfg, qkeys)
@@ -592,17 +596,26 @@ def make_batched_engine(cfg: MSLRUConfig, max_rounds: int | None = None,
                                      chain_live=live, costs=costs)
         return table, res
 
-    def run(table, qkeys, qvals, ops=None, chain_ids=None, costs=None):
-        if ops is not None:
-            ops = jnp.asarray(ops, jnp.int32)
-        if costs is not None:
-            costs = jnp.asarray(costs, jnp.int32)
-        if chain_ids is not None:
-            assert ops is not None, "chain_ids requires an ops vector"
-            return run_chain(table, qkeys, qvals, ops,
-                             jnp.asarray(chain_ids, jnp.int32), costs)
-        return run_ops(table, qkeys, qvals, ops, costs)
+    def dispatch(**jit_kw):
+        ops_call = jax.jit(run_ops, **jit_kw)
+        chain_call = jax.jit(run_chain, **jit_kw)
 
+        def run(table, qkeys, qvals, ops=None, chain_ids=None, costs=None):
+            if ops is not None:
+                ops = jnp.asarray(ops, jnp.int32)
+            if costs is not None:
+                costs = jnp.asarray(costs, jnp.int32)
+            if chain_ids is not None:
+                assert ops is not None, "chain_ids requires an ops vector"
+                return chain_call(table, qkeys, qvals, ops,
+                                  jnp.asarray(chain_ids, jnp.int32), costs)
+            return ops_call(table, qkeys, qvals, ops, costs)
+
+        run.run_ops, run.run_chain = ops_call, chain_call
+        return run
+
+    run = dispatch()
+    run.donating = dispatch(donate_argnums=0)
     return run
 
 

@@ -142,6 +142,40 @@ def test_onepass_engine_compiles_at_table_size(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < TABLE_BYTES // 4
 
 
+def _table_copies(compiled, table_shape) -> list:
+    """Instructions that copy the entry's table parameter, or make another
+    array of the table's shape by a copy."""
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY"):]
+    param = re.search(r"%(\S+) = \S+ parameter\(0\)", entry).group(1)
+    shape = r"s32\[{}\]".format(",".join(map(str, table_shape)))
+    return re.findall(rf"^.*= (?:{shape}\S* copy\(|\S+ copy\(%{param}\)).*$",
+                      text, re.M)
+
+
+def test_owned_table_is_donated_at_cell_size(one_chip):
+    """The stateful cache's batched call at the benchmark cell's geometry
+    (2**25 sets, 8 lanes, two value planes, batches of 8192 with ops)
+    writes the new table into the donated one: no copy of the 3.22 GB
+    table, which the functional call, whose caller keeps its table, has."""
+    from repro.core.engine import make_batched_engine
+    cfg = MSLRUConfig(num_sets=2**25, m=2, p=4, value_planes=2)
+    shape = (cfg.num_sets, cfg.assoc, cfg.planes)
+    step = make_batched_engine(cfg, engine="onepass", use_kernel=True,
+                               interpret=False)
+    args = (_spec(one_chip, shape),
+            _spec(one_chip, (BATCH, cfg.key_planes)),
+            _spec(one_chip, (BATCH, cfg.value_planes)),
+            _spec(one_chip, (BATCH,)), None)
+    owned = step.donating.run_ops.lower(*args).compile()
+    _assert_kernel(owned)
+    assert owned.memory_analysis().alias_size_in_bytes == 3_221_225_472
+    assert not _table_copies(owned, shape)
+    lent = step.run_ops.lower(*args).compile()
+    assert lent.memory_analysis().alias_size_in_bytes == 0
+    assert _table_copies(lent, shape)
+
+
 def test_sequential_oracle_compiles_at_table_size(one_chip):
     """The oracle phase b checks against: its scan carries the table in a
     row layout (one padded copy, about 2 GB), which must still leave the
