@@ -9,6 +9,7 @@ import dataclasses
 import json
 import shutil
 import tempfile
+import time
 
 from bench import common, peaks as peaks_mod
 
@@ -119,6 +120,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, clock,
     device = dict(outcome.device)
     if trace:
         from bench import trace as trace_mod
+        t = time.perf_counter()
         summary = trace_mod.reduce_dir(ctx.trace_dir, len(devices))
         shutil.rmtree(ctx.trace_dir, ignore_errors=True)
         metrics = {}
@@ -130,6 +132,7 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, clock,
         device["busy_s"] = summary.busy_s
         device["window_s"] = summary.window_s
         breakdown = summary.breakdown()
+        common.log(f"[bench] trace reduced in {time.perf_counter() - t:.1f} s")
     else:
         metrics = {name: {"value": v, "unit": u}
                    for name, (v, u) in outcome.metrics.items()}

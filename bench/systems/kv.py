@@ -15,14 +15,13 @@ are compared with the program's.
 
 from __future__ import annotations
 
+import collections
 import gc
-import queue
-import threading
 import time
 
 import numpy as np
 
-from bench import common
+from bench import common, kv_ring
 from bench.counts import kv_row_bytes
 from bench.generators import ycsb
 from bench.harness import Check, Outcome
@@ -30,6 +29,12 @@ from bench.reference import kvcache as ref
 
 # answers a replayed sample must hold before a run can be called correct
 MIN_CHECKED = 1000
+# the most operations a second a window may answer on each chip: its host
+# buffers are sized from this before it opens, and a run that dispatches
+# more batches fails.  Twice the one-chip cell's rate (2.8 M, the host
+# setting the pace), and one and a half times what its engine's device time
+# alone would allow (4.0 M).
+MAX_OPS_PER_S_PER_CHIP = 6_000_000
 
 
 class _Engine:
@@ -63,6 +68,7 @@ class _Engine:
                                      mesh, P("cache", None, None)))()
 
     def put(self, x):
+        """``x`` (an array or a tuple of them) on the engine's devices."""
         import jax
         return jax.device_put(x, self.sharding)
 
@@ -158,7 +164,6 @@ def _sample_sets(spec, traffic, stream: ycsb.Stream, seed: int) -> np.ndarray:
 
 
 def run(ctx) -> Outcome:
-    import jax
     spec, traffic = ctx.config, ctx.traffic
     key_space = int(spec["key_space"])
     eng = _Engine(spec, ctx.devices)
@@ -172,83 +177,39 @@ def run(ctx) -> Outcome:
                f"{eng.chips} chip(s); filled with {calls} calls in "
                f"{time.perf_counter() - t:.3f} s")
 
-    def device_batch(keys, ops):
-        vals = ycsb.record_values(keys)
-        return (eng.put(keys), eng.put(vals),
-                eng.put(eng.ops_map[ops.astype(np.int64)]))
-
-    # warm the window's programs on batches of another stream
-    warm = ycsb.Stream(traffic, key_space, batch, ctx.seed + 1)
-    for i in range(2):
-        eng.fetch(eng.dispatch(*device_batch(*warm.batch(i))))
-    sets = _sample_sets(spec, traffic, stream, ctx.seed)
-    rows_before = _sample_rows(eng.table_now, sets)
-
-    # the generator runs ahead on a thread of its own
-    q: queue.Queue = queue.Queue(maxsize=4 * in_flight)
-    stop = threading.Event()
-
-    def produce():
-        i = 0
-        while not stop.is_set():
-            item = (i,) + stream.batch(i)
-            while not stop.is_set():
-                try:
-                    q.put(item, timeout=0.05)
-                    break
-                except queue.Full:
-                    continue
-            i += 1
-
-    producer = threading.Thread(target=produce, daemon=True)
-    producer.start()
-    while q.qsize() < q.maxsize:
-        time.sleep(0.001)
-
-    batches, answers, latency = [], [], []
-    gen_wait = 0.0
-    completed = 0
-    pending = []
-    at_open = ctx.open_window()
-    with ctx.traced():
-        t_open = time.perf_counter()
-        t_close = t_open + ctx.seconds
-        while True:
-            while len(pending) < in_flight and time.perf_counter() < t_close:
-                tw = time.perf_counter()
-                with jax.profiler.TraceAnnotation("gen_wait"):
-                    _, keys, ops = q.get()
-                gen_wait += time.perf_counter() - tw
-                with jax.profiler.TraceAnnotation("dispatch"):
-                    t_d = time.perf_counter()
-                    res = eng.dispatch(*device_batch(keys, ops))
-                pending.append((t_d, res))
-                batches.append((keys, ops))
-            if not pending:
-                break
-            t_d, res = pending.pop(0)
-            with jax.profiler.TraceAnnotation("fetch"):
-                host = eng.fetch(res)
-            t_r = time.perf_counter()
-            answers.append(host)
-            if t_r <= t_close:
-                completed += 1
-                latency.append(t_r - t_d)
-        ctx.close_window(at_open)
-    stop.set()
-    producer.join(timeout=10)
+    # the window's batches come from a child process, through shared memory
+    ring = kv_ring.BatchRing(traffic, key_space, batch, ctx.seed,
+                             eng.ops_map)
+    try:
+        # warm the window's programs on batches of another stream
+        warm = ycsb.Stream(traffic, key_space, batch, ctx.seed + 1)
+        for i in range(2):
+            host = eng.fetch(eng.dispatch(*eng.put(
+                kv_ring.make_batch(warm, i, eng.ops_map))))
+        sets = _sample_sets(spec, traffic, stream, ctx.seed)
+        rows_before = _sample_rows(eng.table_now, sets)
+        max_batches = -(-int(ctx.seconds * MAX_OPS_PER_S_PER_CHIP
+                             * eng.chips) // batch) + in_flight
+        win = _Window(max_batches, batch, host)
+        ring.wait_full()
+        stats = _window(ctx, eng, ring, win, in_flight)
+    finally:
+        ring.close()
+    n, completed = stats["dispatched"], stats["completed"]
+    update_code = eng.ops_map[ycsb.UPDATE]
 
     device = common.device_stamp(ctx.devices)
     rows_after = _sample_rows(eng.table_now, sets)
     del eng
     gc.collect()
 
-    keys = np.concatenate([b[0] for b in batches])
-    ops = np.concatenate([b[1] for b in batches])
-    fields = {f: np.concatenate([a[f] for a in answers])
-              for f in answers[0] if f != "served"}
-    unserved = sum(int((~a["served"]).sum()) for a in answers
-                   if "served" in a)
+    keys = win.keys[:n].reshape(-1)
+    ops = np.where(win.ops[:n].reshape(-1) == update_code, ycsb.UPDATE,
+                   ycsb.READ).astype(np.int8)
+    fields = {f: a[:n].reshape((n * batch,) + a.shape[2:])
+              for f, a in win.answers.items() if f != "served"}
+    unserved = (int((~win.answers["served"][:n]).sum())
+                if "served" in win.answers else 0)
     t = time.perf_counter()
     key_sets = ref.fmix32_sets(keys, spec["num_sets"])
     bad, checked, final = ref.replay(
@@ -259,16 +220,16 @@ def run(ctx) -> Outcome:
         ctx.keep.update(rows_before=_rows_dict(rows_before, sets), p=spec["p"],
                         keys=keys, ops=ops, sets=key_sets, final=final,
                         answers=fields)
-    common.log(f"[kv] window: {len(batches)} batches dispatched, "
-               f"{completed} answered in {ctx.seconds} s; generator waited "
-               f"{gen_wait:.4f} s; reference replayed {checked} operations "
-               f"of {len(sets)} sets in {time.perf_counter() - t:.2f} s")
+    _log_window(stats, batch, ctx.seconds, win)
+    common.log(f"[kv] reference replayed {checked} operations of {len(sets)} "
+               f"sets in {time.perf_counter() - t:.2f} s")
 
     ops_done = completed * batch
+    latency = win.latency[:completed]
     per_batch_sets = [np.unique(key_sets[j * batch:(j + 1) * batch]).size
-                      for j in range(0, len(batches), max(1, len(batches) // 64))]
+                      for j in range(0, n, max(1, n // 64))]
     counts = {
-        "kv_batches": len(batches),
+        "kv_batches": n,
         "kv_batch_queries": batch,
         "kv_distinct_sets_mean": float(np.mean(per_batch_sets)),
         "kv_row_bytes": kv_row_bytes(spec["m"] * spec["p"],
@@ -280,7 +241,7 @@ def run(ctx) -> Outcome:
     metrics = {
         "kv_ops_per_s": (ops_done / ctx.seconds, "ops/s"),
         "kv_p99_ms": (common.percentile(latency, 99) * 1e3
-                      if latency else float("inf"), "ms"),
+                      if latency.size else float("inf"), "ms"),
         "setup_s": (ctx.setup_s, "s"),
     }
     checks = {
@@ -289,8 +250,128 @@ def run(ctx) -> Outcome:
         "kv_ops_shed": Check(unserved, 0),
         "kv_answers_checked": Check(checked, MIN_CHECKED, ">="),
     }
-    return Outcome(metrics, counts, checks, attempted=len(batches) * batch,
+    return Outcome(metrics, counts, checks, attempted=n * batch,
                    failed=unserved, device=device)
+
+
+class _Window:
+    """Host memory for what the window keeps, allocated and written once
+    before it opens, so the timed loop only copies into it: each batch's
+    keys and op codes and its answers (``template`` is one batch's), and
+    each answered batch's latency and completion time."""
+
+    def __init__(self, max_batches: int, batch: int, template: dict):
+        self.max_batches = max_batches
+        self.keys = _touched((max_batches, batch), np.int32)
+        self.ops = _touched((max_batches, batch), np.int32)
+        self.answers = {f: _touched((max_batches,) + np.shape(a),
+                                    np.asarray(a).dtype)
+                        for f, a in template.items()}
+        self.latency = _touched((max_batches,), np.float64)
+        self.done_at = _touched((max_batches,), np.float64)
+
+    def keep(self, j: int, keys, ops, host: dict):
+        self.keys[j] = keys
+        self.ops[j] = ops
+        for f, buf in self.answers.items():
+            buf[j] = host[f]
+
+
+def _touched(shape, dtype) -> np.ndarray:
+    a = np.empty(shape, dtype)
+    a.fill(0)                 # every page is mapped now, not in the window
+    return a
+
+
+def _window(ctx, eng: _Engine, ring, win: _Window, in_flight: int) -> dict:
+    """The closed loop: keeps ``in_flight`` batches dispatched; a batch
+    counts once its results are on the host.  Batches dispatched before the
+    window closes are all kept, also those answered after it."""
+    import jax
+    pending = collections.deque()
+    n = completed = 0
+    gen_wait = dispatch_s = fetch_s = 0.0
+    host_before = _host_counters()
+    at_open = ctx.open_window()
+    with ctx.traced():
+        t_close = time.perf_counter() + ctx.seconds
+        while True:
+            while len(pending) < in_flight and time.perf_counter() < t_close:
+                if n == win.max_batches:
+                    raise RuntimeError(
+                        f"the window dispatched {n} batches, the most its "
+                        f"buffers hold ({MAX_OPS_PER_S_PER_CHIP} ops/s a "
+                        f"chip); raise MAX_OPS_PER_S_PER_CHIP")
+                tw = time.perf_counter()
+                with jax.profiler.TraceAnnotation("gen_wait"):
+                    keys, vals, ops = ring.take(n)
+                t_d = time.perf_counter()
+                gen_wait += t_d - tw
+                with jax.profiler.TraceAnnotation("dispatch"):
+                    res = eng.dispatch(*eng.put((keys, vals, ops)))
+                dispatch_s += time.perf_counter() - t_d
+                pending.append((n, t_d, res, keys, ops))
+                n += 1
+            if not pending:
+                break
+            j, t_d, res, keys, ops = pending.popleft()
+            t_f = time.perf_counter()
+            with jax.profiler.TraceAnnotation("fetch"):
+                host = eng.fetch(res)
+            t_r = time.perf_counter()
+            fetch_s += t_r - t_f
+            win.keep(j, keys, ops, host)
+            ring.release()
+            if t_r <= t_close:
+                win.latency[completed] = t_r - t_d
+                win.done_at[completed] = t_r
+                completed += 1
+        ctx.close_window(at_open)
+    steal, switches = (b - a for a, b in zip(host_before, _host_counters()))
+    return {"dispatched": n, "completed": completed, "gen_wait": gen_wait,
+            "dispatch_s": dispatch_s, "fetch_s": fetch_s, "steal_s": steal,
+            "switches": switches}
+
+
+def _host_counters() -> tuple:
+    """(CPU time the hypervisor took from this machine's CPUs, summed, s;
+    involuntary context switches of this process): what the host took
+    from the run."""
+    import os
+    import resource
+    try:
+        with open("/proc/stat") as f:
+            steal = int(f.readline().split()[8]) / os.sysconf("SC_CLK_TCK")
+    except (OSError, IndexError, ValueError):
+        steal = float("nan")
+    return steal, resource.getrusage(resource.RUSAGE_SELF).ru_nivcsw
+
+
+def _log_window(stats: dict, batch: int, seconds: float, win: _Window):
+    """The window's end-to-end numbers with what explains their spread:
+    the generator's wait, the host's time in dispatch and fetch, the gaps
+    between successive completions (the longest with their offsets), and
+    what the host took from the run."""
+    completed = stats["completed"]
+    latency = win.latency[:completed]
+    done_at = win.done_at[:completed]
+    gaps = np.diff(done_at) * 1e3 if completed > 1 else np.zeros(1)
+    p99 = common.percentile(latency, 99) * 1e3 if completed else float("inf")
+    common.log(f"[kv] window: kv_ops_per_s {completed * batch / seconds} "
+               f"kv_p99_ms {p99}; {stats['dispatched']} batches dispatched, "
+               f"{completed} answered in {seconds} s; generator waited "
+               f"{stats['gen_wait']:.4f} s, dispatch {stats['dispatch_s']:.4f}"
+               f" s, fetch {stats['fetch_s']:.4f} s; completion gaps ms p50 "
+               f"{common.percentile(gaps, 50):.4f} p99 "
+               f"{common.percentile(gaps, 99):.4f} max {gaps.max():.4f}, "
+               f"{int((gaps > 50).sum())} over 50 ms; CPU steal "
+               f"{stats['steal_s']:.2f} s (all CPUs), {stats['switches']} "
+               f"involuntary switches")
+    long = [f"{done_at[j] - done_at[0]:.3f}s:{gaps[j]:.1f}ms"
+            for j in np.flatnonzero(gaps > 50)[:10]]
+    if long:
+        common.log(f"[kv] window: gaps over 50 ms (at, length) "
+                   f"{' '.join(long)}")
 
 
 def _result_bytes(spec, chips: int) -> int:

@@ -62,3 +62,74 @@ def test_interval_helpers():
     assert trace.clip([(0, 10)], [(2, 3), (5, 6)]) == [(2, 3), (5, 6)]
     assert trace.op_label("%copy.39 = s32[8,3]{0,1:T(8,128)} copy(s32[8,3] "
                           "%table.1)") == "copy.39 copy"
+
+
+def _host_at_scan(summary, t):
+    """The innermost host span open at ``t`` by a scan of every span."""
+    best, size = trace.WINDOW, None
+    for n, s, e in summary.host:
+        if s <= t < e and n != trace.WINDOW and (size is None or e - s < size):
+            best, size = n, e - s
+    return best
+
+
+def _clip_scan(intervals, within):
+    return [(max(s, a), min(e, b)) for s, e in intervals for a, b in within
+            if min(e, b) > max(s, a)]
+
+
+@pytest.mark.parametrize("name", ["kv_small", "kv_phases_small"])
+def test_sweeps_give_the_scans_answers(name):
+    """On the recorded traces the sweep and the bisection answer exactly
+    as a scan of every span and every interval does."""
+    summary = trace.reduce_file(str(DATA.parent / f"{name}.xplane.pb"))
+    busy = trace.merge([(s, e) for _, s, e in summary.ops[0]])
+    gaps, t = [], summary.window[0]
+    for s, e in busy + [(summary.window[1], summary.window[1])]:
+        if s > t:
+            gaps.append((_host_at_scan(summary, (t + s) / 2), (s - t) * 1e-9))
+        t = max(t, e)
+    assert summary.idle_gaps() == gaps
+    assert summary.breakdown()["idle_gaps"] == [
+        list(x) for x in sorted(gaps, key=lambda x: -x[1])[:10]]
+    edges = sorted({x for _, s, e in summary.host for x in (s, e, s - 1)})
+    assert summary.hosts_at(edges) == [_host_at_scan(summary, x)
+                                       for x in edges]
+    ops = [(s, e) for _, s, e in summary.ops[0]]
+    for span in ("dispatch", "fetch", "gen_wait"):
+        ivs = trace.merge(summary.spans(span))
+        assert trace.clip(ops, ivs) == _clip_scan(ops, ivs)
+        assert summary.busy_within(summary.spans(span)) == (
+            trace.union_ns(_clip_scan(ops, ivs)) * 1e-9,
+            sum(e - s for s, e in ivs) * 1e-9)
+
+
+def test_reduction_of_a_long_window_is_quick():
+    """100,000 idle gaps against 30,000 nested host spans: a scan of every
+    span for every gap would take thousands of seconds."""
+    import time
+    blocks, n_ops = 10_000, 100_001
+    ops = [("op", k * 100.0, k * 100.0 + 50) for k in range(n_ops)]
+    end = ops[-1][2]
+    host = [("window", 0.0, end)]
+    for j in range(blocks):
+        b = j * 1000.0
+        host += [("dispatch", b, b + 900), ("msl.access", b + 100, b + 800),
+                 ("fetch", b + 300, b + 400)]
+    summary = trace.Summary((0.0, end), {0: ops}, {}, host)
+    t = time.perf_counter()
+    gaps = summary.idle_gaps()
+    busy, length = summary.busy_within(summary.spans("msl.access"))
+    assert time.perf_counter() - t < 5
+    assert len(gaps) == 100_000
+
+    def want(k):                      # the gap after op k, by its midpoint
+        o = (k * 100 + 75) % 1000
+        if 300 <= o < 400:
+            return "fetch"
+        if 100 <= o < 800:
+            return "msl.access"
+        return "dispatch" if o < 900 else "window"
+    assert [n for n, _ in gaps] == [want(k) for k in range(100_000)]
+    assert length == pytest.approx(blocks * 700e-9)
+    assert busy == pytest.approx(blocks * 7 * 50e-9)

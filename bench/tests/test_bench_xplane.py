@@ -79,10 +79,12 @@ def test_scope_seconds_by_hand():
                                 "jit_run_ops") == pytest.approx(8e-9)
     assert xplane.scope_seconds(summary, ops_tf, "msl.chain",
                                 "jit_run_ops") == 0.0
-    # the bisection agrees with the plain clip of the trace reduction
+    # the bisection agrees with a plain scan of every run
     runs = trace.merge([(0, 10), (20, 30), (30, 35), (50, 60)])
     ivs = [(5, 25), (12, 18), (-5, 2), (34, 55), (60, 70)]
-    assert xplane.within(ivs, runs) == trace.clip(ivs, runs)
+    scan = [(max(s, a), min(e, b)) for s, e in ivs for a, b in runs
+            if min(e, b) > max(s, a)]
+    assert trace.clip(ivs, runs) == scan
     empty = trace.Summary((0, 1), {}, {}, [])
     assert xplane.scope_seconds(empty, {}, "msl.order", "jit_run_ops") == 0.0
 
@@ -105,8 +107,8 @@ def test_phases_of_a_scoped_trace():
     runs = trace.merge([(s, e) for n, s, e in summary.modules[0]
                         if program in n])
     ops = summary.ops[0]
-    busy = trace.union_ns(xplane.within([(s, e) for _, s, e in ops], runs))
-    unscoped = trace.union_ns(xplane.within(
+    busy = trace.union_ns(trace.clip([(s, e) for _, s, e in ops], runs))
+    unscoped = trace.union_ns(trace.clip(
         [(s, e) for n, s, e in ops if "/msl." not in ops_tf[0].get(n, "")],
         runs))
     assert sum(phases.values()) == pytest.approx((busy - unscoped) * 1e-9,

@@ -11,8 +11,10 @@ the reduction.
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import glob
+import heapq
 import os
 import re
 
@@ -85,17 +87,32 @@ class Summary:
         gaps, t = [], self.window[0]
         for s, e in busy + [(self.window[1], self.window[1])]:
             if s > t:
-                gaps.append((self.host_at((t + s) / 2), (s - t) * 1e-9))
+                gaps.append(((t + s) / 2, (s - t) * 1e-9))
             t = max(t, e)
-        return gaps
+        names = self.hosts_at([m for m, _ in gaps])
+        return [(n, g) for n, (_, g) in zip(names, gaps)]
 
-    def host_at(self, t) -> str:
-        """The innermost host span open at ``t``."""
-        best, size = WINDOW, None
-        for n, s, e in self.host:
-            if s <= t < e and n != WINDOW and (size is None or e - s < size):
-                best, size = n, e - s
-        return best
+    def hosts_at(self, times) -> list:
+        """The innermost (shortest) host span open at each of ``times``,
+        the first in ``host`` among equals, and ``WINDOW`` where none is.
+        One sweep in time order: O((times + spans) log spans)."""
+        spans = sorted((s, e - s, k, n, e)
+                       for k, (n, s, e) in enumerate(self.host) if n != WINDOW)
+        order = sorted(range(len(times)), key=lambda q: times[q])
+        out = [WINDOW] * len(times)
+        open_, i = [], 0
+        for q in order:
+            t = times[q]
+            while i < len(spans) and spans[i][0] <= t:
+                _, size, k, n, e = spans[i]
+                heapq.heappush(open_, (size, k, n, e))
+                i += 1
+            # a span ended by t is ended for every later query
+            while open_ and open_[0][3] <= t:
+                heapq.heappop(open_)
+            if open_:
+                out[q] = open_[0][2]
+        return out
 
     def breakdown(self) -> dict:
         per_op: dict = {}
@@ -136,13 +153,17 @@ def union_ns(intervals) -> float:
 
 
 def clip(intervals, within) -> list:
-    """Parts of ``intervals`` inside the merged, sorted ``within``."""
+    """Parts of ``intervals`` inside the merged, sorted ``within``, in the
+    order of ``intervals`` and then of ``within``."""
+    ends = [b for _, b in within]
     out = []
     for s, e in intervals:
-        for a, b in within:
-            lo, hi = max(s, a), min(e, b)
+        j = bisect.bisect_right(ends, s)        # the first that ends after s
+        while j < len(within) and within[j][0] < e:
+            lo, hi = max(s, within[j][0]), min(e, within[j][1])
             if hi > lo:
                 out.append((lo, hi))
+            j += 1
     return out
 
 
@@ -195,9 +216,10 @@ def reduce_dir(trace_dir: str, chips: int) -> Summary:
     return summary
 
 
-# host spans the harness and the systems write
+# host spans the harness, the systems and the program (``msl.access``, one
+# cache engine call) write
 HOST_SPANS = {WINDOW, "dispatch", "fetch", "gen_wait", "submit", "step",
-              "sleep", "engine_busy", "record"}
+              "sleep", "engine_busy", "msl.access"}
 
 
 @dataclasses.dataclass

@@ -23,10 +23,9 @@ and fall under no scope.
 
 from __future__ import annotations
 
-import bisect
 import re
 
-from bench.trace import merge, union_ns
+from bench.trace import clip, merge, union_ns
 
 TF_OP = "tf_op"
 
@@ -108,21 +107,6 @@ def tf_ops(path: str) -> dict:
     return out
 
 
-def within(intervals, runs) -> list:
-    """Parts of ``intervals`` inside ``runs`` (merged and sorted), found by
-    bisection: a window holds thousands of program runs."""
-    starts = [a for a, _ in runs]
-    out = []
-    for s, e in intervals:
-        j = max(bisect.bisect_right(starts, s) - 1, 0)
-        while j < len(runs) and runs[j][0] < e:
-            lo, hi = max(s, runs[j][0]), min(e, runs[j][1])
-            if hi > lo:
-                out.append((lo, hi))
-            j += 1
-    return out
-
-
 def scope_seconds(summary, ops_tf: dict, scope: str, program: str) -> float:
     """Device seconds per chip of the ops, inside the runs of the programs
     whose module name matches ``program``, whose ``tf_op`` path has the
@@ -138,5 +122,5 @@ def scope_seconds(summary, ops_tf: dict, scope: str, program: str) -> float:
                       if rx.search(n)])
         names = ops_tf.get(dev, {})
         mine = [(s, e) for n, s, e in evs if seg in names.get(n, "")]
-        tot += union_ns(within(mine, runs))
+        tot += union_ns(clip(mine, runs))
     return tot * 1e-9 / len(summary.ops)
